@@ -28,6 +28,7 @@ from dataclasses import dataclass, replace
 from typing import Mapping, Sequence
 
 from repro.core.algebra import SemiJoin
+from repro.obs.tracer import host_read
 
 BYTES_PER_CELL = 4  # engine values are int32
 MB = 1e6
@@ -336,7 +337,7 @@ def stats_of_db(db, sel=None, default_sel: float = 0.5, *,
     hh_of = _heavy_hitters_of if heavy_hitters > 0 else (lambda r, k: ())
     rels = {
         name: RelStats(
-            rows=float(r.count()),
+            rows=float(host_read(r.count(), "costmodel.stats")),
             arity=r.arity,
             heavy_hitters=hh_of(r, heavy_hitters),
         )

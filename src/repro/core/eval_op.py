@@ -75,9 +75,10 @@ def run_eval(
 ):
     """Execute one EVAL job. Returns ``({name: Relation}, stats)``.
 
-    ``tracer`` records the two pipeline phases (``eval.shuffle`` — tuple
-    routing + exchange — and ``eval.reduce`` — sorted grouping + formula
-    evaluation); ``None`` runs the exact untraced path (DESIGN.md §14).
+    The two pipeline phases run under profiler spans (``eval.shuffle`` —
+    tuple routing + exchange — and ``eval.reduce`` — sorted grouping +
+    formula evaluation), which ``tracer`` also records as phase spans
+    (DESIGN.md §14); outputs are the same with or without it.
     """
     P = comm.P
     units = tuple(units)
@@ -184,9 +185,6 @@ def run_eval(
         return None, (outs, stats)
 
     stacked = {name: env[name] for name in rel_names}
-    traced = tracer is not None and getattr(tracer, "enabled", False)
-    phase_spans = tracer.current() if traced else []
-    base = len(phase_spans)
     outputs, stats = run_pipeline(
         comm, [stage_map, stage_reduce], stacked,
         tracer=tracer, names=["eval.shuffle", "eval.reduce"],
@@ -194,8 +192,4 @@ def run_eval(
     stats = {k: jnp.asarray(v).sum() for k, v in stats.items()}
     stats["bytes_fwd"] = stats["sent_fwd"] * W * 4
     stats["bytes_bwd"] = jnp.int32(0)
-    if traced:
-        for sp in phase_spans[base:]:
-            if sp.name == "eval.shuffle":
-                sp.args["bytes"] = int(stats["bytes_fwd"])
     return outputs, stats

@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import math
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field, replace
 from typing import Callable, Iterable, Sequence
 
@@ -84,7 +85,14 @@ from repro.core.planner import (
 )
 from repro.core.relation import Relation
 from repro.engine.comm import Comm
-from repro.obs.tracer import Span, rebase as _rebase_spans, scale_spans as _scale_spans
+from repro.obs.tracer import (
+    Span,
+    annotate,
+    host_read,
+    phase,
+    rebase as _rebase_spans,
+    scale_spans as _scale_spans,
+)
 
 
 class CapacityFault(RuntimeError):
@@ -221,10 +229,35 @@ class ScheduledJob:
 
 def int_stats(stats: dict) -> tuple[dict, str]:
     """Coerce job stats to host ints, splitting off the probe-backend tag
-    (the one non-numeric entry :meth:`Executor.run_job` records)."""
+    (the one non-numeric entry :meth:`Executor.run_job` records).  Each
+    device-resident stat is one host read."""
     s = dict(stats)
     backend = str(s.pop("backend", ""))
-    return {k: int(v) for k, v in s.items()}, backend
+    return {k: int(host_read(v, "executor.stats")) for k, v in s.items()}, backend
+
+
+#: phase span -> (span arg, job stat) attached after the job from its
+#: host-read stats, so the pipeline stages themselves read nothing
+SPAN_STATS = {
+    "msj.shuffle.fwd": ("bytes", "bytes_fwd"),
+    "msj.xfer": ("bytes", "bytes_fwd"),
+    "msj.scatter": ("bytes", "bytes_bwd"),
+    "msj.probe": ("hits", "hits"),
+    "eval.shuffle": ("bytes", "bytes_fwd"),
+}
+
+
+def job_stats(stats: dict, spans: list[Span]) -> tuple[dict, str]:
+    """:func:`int_stats`, and the shuffled bytes and hits copied onto the
+    phase spans of the attempt that produced them (the last ``ft.attempt``
+    of ``spans``, empty when no tracer is held)."""
+    ints, backend = int_stats(stats)
+    if spans:
+        for sp in spans[-1].walk():
+            if sp.name in SPAN_STATS:
+                arg, key = SPAN_STATS[sp.name]
+                sp.args[arg] = ints.get(key, 0)
+    return ints, backend
 
 
 def left_sum(values: Iterable[float]) -> float:
@@ -682,10 +715,9 @@ class Executor:
         self.comm = comm
         self.config = config or ExecutorConfig()
         self.stats = stats
-        #: phase-span tracer (repro.obs.Tracer) — None (default) keeps the
-        #: hot path bit-identical to the untraced build; enabled tracing
-        #: syncs per pipeline stage so spans carry honest device time
-        #: (DESIGN.md §14).
+        #: phase-span tracer (repro.obs.Tracer): records each job attempt's
+        #: phases as Spans on its JobRecord.  The profiler spans run either
+        #: way, and neither changes what is dispatched (DESIGN.md §14).
         self.tracer = tracer
         #: metric registry (repro.obs.MetricRegistry) — when present,
         #: execute() publishes msj.*/ft.* counters from each report.
@@ -764,9 +796,7 @@ class Executor:
                 cap_slack=self.config.cap_slack if cap_slack is None else cap_slack,
                 tracer=self.tracer,
             )
-            stats["input_rows"] = sum(
-                int(self.env[r].count()) for r in _msj_input_rels(job, self.env)
-            )
+            stats["input_rows"] = self._rows(_msj_input_rels(job, self.env))
             stats["backend"] = backend
             return outs, stats
         if isinstance(job, SkewProfileJob):
@@ -794,9 +824,7 @@ class Executor:
                     1 for _, fps in table.counts
                     for _, n in fps if n >= table.threshold
                 ),
-                "input_rows": sum(
-                    int(self.env[r].count()) for r in job_reads(job)
-                ),
+                "input_rows": self._rows(job_reads(job)),
             }
             return {job.salt: table}, stats
         if isinstance(job, TransferJob):
@@ -835,9 +863,7 @@ class Executor:
                 tracer=self.tracer,
                 skew=skew,
             )
-            stats["input_rows"] = sum(
-                int(self.env[r].count()) for r in _msj_input_rels(job.base, self.env)
-            )
+            stats["input_rows"] = self._rows(_msj_input_rels(job.base, self.env))
             return ({job.buffer: buf} if job.buffer else {}), stats
         if isinstance(job, ComputeJob):
             # compute sub-node: probe + scatter against the buffered
@@ -882,10 +908,17 @@ class Executor:
                     salt=query_salt(q),
                 )
             )
-            input_rows += int(env[x0].count()) + sum(int(self.env[x].count()) for x in xin)
+            input_rows += self._rows([x0], env) + self._rows(xin)
         outs, stats = run_eval(env, units, self.comm, tracer=self.tracer)
         stats["input_rows"] = input_rows
         return outs, stats
+
+    def _rows(self, names, env=None) -> int:
+        """Valid rows of the named relations, one host read each."""
+        env = self.env if env is None else env
+        return sum(
+            int(host_read(env[r].count(), "executor.input_rows")) for r in names
+        )
 
     def run_job_ft(
         self,
@@ -906,25 +939,16 @@ class Executor:
         stays in force for later jobs and plans).
         """
         state = RetryState() if state is None else state
-        tr = self.tracer
-        traced = tr is not None and getattr(tr, "enabled", False)
         attempts = 0
         while True:
             attempts += 1
             sp = None
             try:
-                if traced:
-                    # one span per dispatch attempt: retries and capacity
-                    # re-runs show up as sibling ft.attempt slices with the
-                    # pipeline phase spans nested inside (DESIGN.md §14)
-                    with tr.span("ft.attempt", cat="attempt",
-                                 attempt=attempts) as sp:
-                        if on_job is not None:
-                            on_job(job, attempts)
-                        outs, stats = self.run_job(
-                            job, cap_override=state.cap, cap_slack=state.slack
-                        )
-                else:
+                # one span per dispatch attempt: retries and capacity
+                # re-runs show up as sibling ft.attempt slices with the
+                # pipeline phase spans nested inside (DESIGN.md §14)
+                with phase(self.tracer, "ft.attempt", cat="attempt",
+                           attempt=attempts) as sp:
                     if on_job is not None:
                         on_job(job, attempts)
                     outs, stats = self.run_job(
@@ -944,7 +968,7 @@ class Executor:
                 if state.fault_retries > max_restarts:
                     raise
                 continue
-            ovf = int(stats.get("overflow", 0))
+            ovf = int(host_read(stats.get("overflow", 0), "executor.overflow"))
             if ovf == 0:
                 if sp is not None:
                     sp.args["outcome"] = "ok"
@@ -1042,18 +1066,9 @@ class Executor:
         rebased to offsets from the dispatch, and scaled by the same
         factor as the wall, so they nest inside the virtual job slice."""
         tr = self.tracer
-        traced = tr is not None and getattr(tr, "enabled", False)
-        spans: list[Span] = []
+        traced = tr is not None and tr.enabled
         t0 = time.perf_counter()
-        if traced:
-            with tr.capture() as spans:
-                outs, stats, attempts = self.run_job_ft(
-                    job, on_job, state=state, max_restarts=max_restarts
-                )
-                if self.config.sync_per_job:
-                    for v in outs.values():
-                        jax.block_until_ready(v.data)
-        else:
+        with tr.capture() if traced else nullcontext([]) as spans:
             outs, stats, attempts = self.run_job_ft(
                 job, on_job, state=state, max_restarts=max_restarts
             )
@@ -1068,14 +1083,18 @@ class Executor:
             _rebase_spans(spans, t0, wall / measured if measured > 0.0 else 1.0)
         return outs, stats, attempts, wall, spans
 
-    def _publish(self, outs: dict) -> None:
-        for name, rel in outs.items():
-            # XferBuffers and SaltTables are in-flight sub-node state, not
-            # relations: never compacted, never committed, dropped from the
-            # env once their consumer sub-node completes
-            if self.config.compact and isinstance(rel, Relation):
-                rel = rel.compacted()
-            self.env[name] = rel
+    def _publish(self, job: Job, outs: dict) -> None:
+        """Commit a job's outputs to the environment, compacted, under an
+        ``msj.publish`` or ``eval.publish`` span by the job's kind."""
+        kind = "eval" if isinstance(job, EvalJob) else "msj"
+        with annotate(f"{kind}.publish"):
+            for name, rel in outs.items():
+                # XferBuffers and SaltTables are in-flight sub-node state,
+                # not relations: never compacted, never committed, dropped
+                # from the env once their consumer sub-node completes
+                if self.config.compact and isinstance(rel, Relation):
+                    rel = rel.compacted()
+                self.env[name] = rel
 
     def execute_job(
         self,
@@ -1092,8 +1111,8 @@ class Executor:
         outs, stats, attempts, wall, spans = self._attempt(
             job, on_job, RetryState(), max_restarts, wall_scale, 0
         )
-        self._publish(outs)
-        ints, backend = int_stats(stats)
+        self._publish(job, outs)
+        ints, backend = job_stats(stats, spans)
         rec = JobRecord(job, round_idx, wall, ints, attempts, backend, spans=spans)
         report.records.append(rec)
         return rec
@@ -1192,10 +1211,6 @@ class Executor:
         # supervisor's: FTStats publishes them under ft.* from the same
         # ft_counters, so publishing here too would double-count when the
         # registry is shared
-        wall = m.histogram("msj.job.wall")
-        for r in report.records:
-            if r.outcome == "ok":
-                wall.observe(r.wall)
 
     def _execute_async(
         self, nodes, slots, est, on_job, max_restarts=0, wall_scale=None
@@ -1386,7 +1401,7 @@ class Executor:
                 # relation must drop even though that relation has a clean
                 # producer (none — it's a base input)
                 tr = self.tracer
-                if tr is not None and getattr(tr, "enabled", False):
+                if tr is not None and tr.enabled:
                     t_sweep = time.perf_counter()
                     n0 = len(report.records)
                     self._taint_sweep(
@@ -1434,8 +1449,8 @@ class Executor:
                             # original attempt already completed
                             clone = None
             if clone is None:
-                self._publish(outs)
-                ints, backend = int_stats(stats)
+                self._publish(node.job, outs)
+                ints, backend = job_stats(stats, spans)
                 rec = JobRecord(node.job, node.round_idx, wall, ints, attempts,
                                 backend, start, end, s, spans=spans)
                 recs = [rec]
@@ -1445,9 +1460,9 @@ class Executor:
                 end2 = t2 + wall2
                 win_end = min(end, end2)  # ties go to the original
                 clone_wins = end2 < end
-                self._publish(outs2 if clone_wins else outs)
-                ints, backend = int_stats(stats)
-                ints2, backend2 = int_stats(stats2)
+                self._publish(node.job, outs2 if clone_wins else outs)
+                ints, backend = job_stats(stats, spans)
+                ints2, backend2 = job_stats(stats2, spans2)
                 # the loser's wall is truncated at the winner's end; its
                 # spans shrink by the same factor so they stay inside the
                 # cancelled slice (the winner's factor is exactly 1.0)

@@ -51,6 +51,7 @@ from repro.core.algebra import Cond, SemiJoin, eval_cond
 from repro.core.relation import Relation
 from repro.engine import hashing, shuffle
 from repro.engine.comm import Comm, run_pipeline
+from repro.obs.tracer import host_read, phase
 
 KIND_ASSERT = 0
 KIND_REQ = 1
@@ -670,7 +671,7 @@ def count_forward_cap(
     counts = run_pipeline(comm, [stage_count], stacked)
     if isinstance(counts, jax.core.Tracer):
         return None
-    cap = int(jnp.max(counts))
+    cap = int(host_read(jnp.max(counts), "msj.count_cap"))
     if slack < 1.0:
         return max(1, int(cap * slack))
     return max(1, cap)
@@ -692,21 +693,16 @@ def _sized_cap(
     count-sized (two-phase, DESIGN.md §6), or worst-case bound.  Returns
     ``(cap, counted)`` where ``counted`` marks a successful count phase
     (its ``P·P`` int32 exchange is then charged to ``bytes_fwd``)."""
-    traced = tracer is not None and getattr(tracer, "enabled", False)
     counted = False
     if forward_cap is not None:
         cap_s = forward_cap
     elif count_sized:
-        if traced:
-            with tracer.span("msj.count") as _sp:
-                cap_s = count_forward_cap(
-                    spec, db, comm, packing=packing, slack=cap_slack, skew=skew
-                )
-                _sp.args["cap"] = cap_s
-        else:
+        with phase(tracer, "msj.count") as sp:
             cap_s = count_forward_cap(
                 spec, db, comm, packing=packing, slack=cap_slack, skew=skew
             )
+            if sp is not None:
+                sp.args["cap"] = cap_s
         counted = cap_s is not None
         if cap_s is None:
             cap_s = default_forward_cap(spec, db, comm.P, cap_slack, skew=skew)
@@ -1042,10 +1038,11 @@ def run_msj(
     deliberately undersizes the chosen capacity (memory saving; exact
     overflow detection + supervisor retry recover correctness).
 
-    ``tracer`` (DESIGN.md §14) records the per-phase spans — ``msj.count``
-    (count exchange), ``msj.bloom``, ``msj.shuffle.fwd`` (map + forward
-    partition), ``msj.probe``, ``msj.scatter``; ``tracer=None`` (the
-    default) runs the exact untraced path.
+    Each phase runs under a profiler span — ``msj.count`` (count
+    exchange), ``msj.bloom``, ``msj.shuffle.fwd`` (map + forward
+    partition), ``msj.probe``, ``msj.scatter`` — which ``tracer``
+    (DESIGN.md §14) also records as phase spans; outputs are the same
+    with or without it.
 
     ``skew`` (DESIGN.md §17) salts hot Req keys across R sub-shards and
     replicates the matching builds; exactness is unchanged (every Req
@@ -1055,7 +1052,6 @@ def run_msj(
     spec = make_spec(sjs, fingerprint=fingerprint)
     if skew is not None:
         skew = skew.live(packing=packing, P=comm.P)
-    traced = tracer is not None and getattr(tracer, "enabled", False)
     cap_s, counted = _sized_cap(
         spec, db, comm,
         packing=packing, forward_cap=forward_cap,
@@ -1073,8 +1069,6 @@ def run_msj(
     names = (["msj.bloom"] if kit.use_bloom else []) + [
         "msj.shuffle.fwd", "msj.probe", "msj.scatter",
     ]
-    phase_spans = tracer.current() if traced else []
-    base = len(phase_spans)
     outputs, stats = run_pipeline(comm, stages, kit.stacked, tracer=tracer, names=names)
     # aggregate stats over shards (sim mode leaves a leading P axis)
     stats = {k: jnp.asarray(v).sum() for k, v in stats.items()}
@@ -1084,17 +1078,6 @@ def run_msj(
     stats["bytes_fwd"] = stats["sent_fwd"] * kit.W * 4 + bytes_count
     stats["bytes_bwd"] = stats["hits"] * 2 * 4
     stats["forward_cap"] = cap_s
-    if traced:
-        # annotate the just-recorded stage spans with the shuffled bytes
-        # (known only after the shard-summed stats materialize; the sync
-        # is bounded to the scalar stats, not the output relations)
-        by_name = {sp.name: sp for sp in phase_spans[base:]}
-        if "msj.shuffle.fwd" in by_name:
-            by_name["msj.shuffle.fwd"].args["bytes"] = int(stats["bytes_fwd"])
-        if "msj.scatter" in by_name:
-            by_name["msj.scatter"].args["bytes"] = int(stats["bytes_bwd"])
-        if "msj.probe" in by_name:
-            by_name["msj.probe"].args["hits"] = int(stats["hits"])
     return outputs, stats
 
 
@@ -1123,8 +1106,8 @@ def run_msj_transfer(
     ``bytes_fwd``, ``forward_cap``); the compute half reports the rest, so
     per-report totals match the unsplit operator exactly.
 
-    Traced runs record the forward exchange as an ``msj.xfer`` span (the
-    comm-track phase name) rather than ``msj.shuffle.fwd``.
+    The forward exchange runs under an ``msj.xfer`` span (the comm-track
+    phase name) rather than ``msj.shuffle.fwd``.
 
     ``skew`` (DESIGN.md §17): the salted/replicated routing lives entirely
     in this half — the compute half probes whatever landed, so a skew
@@ -1133,7 +1116,6 @@ def run_msj_transfer(
     spec = make_spec(sjs, fingerprint=fingerprint)
     if skew is not None:
         skew = skew.live(packing=packing, P=comm.P)
-    traced = tracer is not None and getattr(tracer, "enabled", False)
     cap_s, counted = _sized_cap(
         spec, db, comm,
         packing=packing, forward_cap=forward_cap,
@@ -1147,8 +1129,6 @@ def run_msj_transfer(
     )
     stages = ([kit.stage_bloom] if kit.use_bloom else []) + [kit.stage_map]
     names = (["msj.bloom"] if kit.use_bloom else []) + ["msj.xfer"]
-    phase_spans = tracer.current() if traced else []
-    base = len(phase_spans)
     carry = run_pipeline(comm, stages, kit.stacked, tracer=tracer, names=names)
     # carry == ((recv, recv_valid), map_carry); the map carry holds the
     # per-shard forward overflow + send/replica-count scalars at fixed
@@ -1164,10 +1144,6 @@ def run_msj_transfer(
     stats["bytes_fwd"] = stats["sent_fwd"] * kit.W * 4 + bytes_count
     stats["bytes_bwd"] = jnp.asarray(0, jnp.int32)
     stats["forward_cap"] = cap_s
-    if traced:
-        by_name = {sp.name: sp for sp in phase_spans[base:]}
-        if "msj.xfer" in by_name:
-            by_name["msj.xfer"].args["bytes"] = int(stats["bytes_fwd"])
     buf = XferBuffer(
         name=name,
         sjs=tuple(sjs),
@@ -1200,14 +1176,11 @@ def run_msj_compute(
     matches the tags the transfer actually shuffled; the executor filters
     the outputs down to the compute node's write set."""
     spec = make_spec(list(buf.sjs), fingerprint=buf.fingerprint)
-    traced = tracer is not None and getattr(tracer, "enabled", False)
     kit = _MSJKit(
         db, spec, comm, buf.cap,
         packing=buf.packing, fused=fused, probe_fn=probe_fn,
         bloom_bits=buf.bloom_bits, fingerprint=buf.fingerprint,
     )
-    phase_spans = tracer.current() if traced else []
-    base = len(phase_spans)
     outputs, stats = run_pipeline(
         comm, [kit.stage_probe, kit.stage_out], buf.data,
         tracer=tracer, names=["msj.probe", "msj.scatter"],
@@ -1222,10 +1195,4 @@ def run_msj_compute(
     stats["bytes_fwd"] = jnp.asarray(0, jnp.int32)
     stats["bytes_bwd"] = stats["hits"] * 2 * 4
     stats["forward_cap"] = buf.cap
-    if traced:
-        by_name = {sp.name: sp for sp in phase_spans[base:]}
-        if "msj.scatter" in by_name:
-            by_name["msj.scatter"].args["bytes"] = int(stats["bytes_bwd"])
-        if "msj.probe" in by_name:
-            by_name["msj.probe"].args["hits"] = int(stats["hits"])
     return outputs, stats

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine import hashing
+from repro.obs.tracer import host_read
 
 
 @jax.tree_util.register_pytree_node_class
@@ -79,7 +80,7 @@ class Relation:
         if partition == "block":
             dest = np.arange(n) % P
         elif partition == "hash":
-            h = np.asarray(hashing.hash_cols(jnp.asarray(rows)))
+            h = host_read(hashing.hash_cols(jnp.asarray(rows)), "relation.from_numpy")
             dest = np.asarray(h) % P
         else:
             raise ValueError(partition)
@@ -144,7 +145,11 @@ class Relation:
         data = self.data if self.data.ndim == 3 else self.data[None]
         valid = self.valid if self.valid.ndim == 2 else self.valid[None]
         if cap is None:
-            per_shard = int(np.asarray(valid.sum(axis=1)).max()) if valid.size else 0
+            per_shard = (
+                int(host_read(valid.sum(axis=1), "relation.compacted").max())
+                if valid.size
+                else 0
+            )
             cap = max(1, int(2 ** np.ceil(np.log2(max(per_shard, 1)))))
         order = jnp.argsort(~valid, axis=1, stable=True)[:, :cap]
         new_data = jnp.take_along_axis(data, order[:, :, None], axis=1)

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs.tracer import phase
+
 
 @dataclass(frozen=True)
 class SimComm:
@@ -119,37 +121,23 @@ def run_pipeline(
     caller is expected to invoke this inside ``shard_map`` (see
     :func:`mesh_pipeline`).
 
-    ``tracer`` (a :class:`repro.obs.Tracer`, DESIGN.md §14) records one
-    phase span per stage (named by ``names``, falling back to the stage
-    function's name).  Tracing must not perturb the dispatch stream it
-    measures: spans bracket the *dispatch* of each stage and the carry is
-    NOT synced between stages — an identical instruction stream to the
-    untraced path, so traced and untraced runs are bit-identical and
-    shuffle/compute overlap (DESIGN.md §16) survives under tracing.  Per
-    stage *device*-time attribution needs a barrier after every stage;
-    opt in via ``Tracer(trace_sync=True)``, which restores the old
-    sync-per-stage behaviour (and serializes any overlap — a measurement
-    mode, never the default).  MeshComm runs inside ``shard_map``, where
-    blocking is impossible; spans there would be trace-side noise, so the
-    tracer is ignored.
+    Every SimComm stage runs under a profiler span (``repro.obs.phase``,
+    DESIGN.md §14) named by ``names``, falling back to the stage
+    function's name, so a trace attributes the executables a stage
+    launches to it; ``tracer`` (a :class:`repro.obs.Tracer`) additionally
+    records each stage as a phase ``Span``.  Neither syncs the carry
+    between stages: the dispatch stream, shuffle/compute overlap
+    (DESIGN.md §16) and the output bits are those of a run without
+    either.  MeshComm runs inside ``shard_map``, where a host span would
+    time the trace, not the run, so it takes no spans.
     """
-    traced = tracer is not None and getattr(tracer, "enabled", False)
-    trace_sync = traced and getattr(tracer, "trace_sync", False)
     if isinstance(comm, SimComm):
         carry = stacked_args
         for i, stage in enumerate(stages):
-            if traced:
-                label = names[i] if names and i < len(names) else getattr(
-                    stage, "__name__", f"stage{i}"
-                )
-                with tracer.span(label):
-                    send, carry = jax.vmap(stage)(comm.shard_ids(), carry)
-                    if send is not None:
-                        recv = jax.tree.map(comm.all_to_all, send)
-                        carry = (recv, carry)
-                    if trace_sync:
-                        carry = jax.block_until_ready(carry)
-            else:
+            label = names[i] if names and i < len(names) else getattr(
+                stage, "__name__", f"stage{i}"
+            )
+            with phase(tracer, label):
                 send, carry = jax.vmap(stage)(comm.shard_ids(), carry)
                 if send is not None:
                     recv = jax.tree.map(comm.all_to_all, send)

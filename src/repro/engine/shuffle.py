@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.obs.tracer import host_read
+
 
 def partition(
     msgs: jnp.ndarray,  # (N, W) int32
@@ -99,10 +101,8 @@ def merge_topk(vals, counts, k: int):
     appear — that is the sketch's only recall loss, bounded by the
     per-shard k (tests/test_skew.py pins the recall floor).
     """
-    import numpy as np
-
-    v = np.asarray(vals).reshape(-1)
-    c = np.asarray(counts).reshape(-1)
+    v = host_read(vals, "shuffle.merge_topk").reshape(-1)
+    c = host_read(counts, "shuffle.merge_topk").reshape(-1)
     totals: dict[int, int] = {}
     for value, count in zip(v.tolist(), c.tolist()):
         if count > 0:

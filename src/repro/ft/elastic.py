@@ -23,6 +23,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro.core.relation import Relation
+from repro.obs.tracer import host_read
 
 
 def reshard_state(state, specs, new_mesh):
@@ -38,8 +39,9 @@ def repartition_relation(rel: Relation, new_P: int, *, partition: str = "block")
     # block-partitioned relation therefore repartitions to the *canonical*
     # placement at the new P (same rows land on the same shards as a fresh
     # from_numpy build), which shard-loss lineage recovery relies on.
-    rows = np.asarray(rel.data).transpose(1, 0, 2).reshape(-1, rel.arity)
-    valid = np.asarray(rel.valid).transpose(1, 0).reshape(-1)
+    rows = host_read(rel.data, "elastic.repartition")
+    rows = rows.transpose(1, 0, 2).reshape(-1, rel.arity)
+    valid = host_read(rel.valid, "elastic.repartition").transpose(1, 0).reshape(-1)
     return Relation.from_numpy(rel.name, rows[valid], P=new_P, partition=partition)
 
 
@@ -83,8 +85,8 @@ def recover_shard(
     if source.cap == damaged.cap:
         sdata, svalid = source.data[shard], source.valid[shard]
     else:
-        rows = np.asarray(source.data[shard])
-        valid = np.asarray(source.valid[shard]).reshape(-1)
+        rows = host_read(source.data[shard], "elastic.recover")
+        valid = host_read(source.valid[shard], "elastic.recover").reshape(-1)
         packed = rows[valid]
         if len(packed) > damaged.cap:
             raise ValueError(

@@ -1,11 +1,14 @@
-"""Observability: phase-span tracing, the metric registry, and the
-Chrome/Perfetto trace exporter (DESIGN.md §14).
+"""Observability: spans on the profiler's clock, phase-span tracing, the
+metric registry, and the Chrome/Perfetto trace exporter (DESIGN.md §14).
 
-* :mod:`repro.obs.tracer` — :class:`Span`/:class:`Tracer`: nested phase
-  spans (count-exchange, forward shuffle, probe, scatter, retry attempts,
-  taint sweeps) hanging off each :class:`~repro.core.executor.JobRecord`.
-  ``tracer=None`` everywhere means *no* tracing code runs — the hot path
-  is bit-identical to the untraced build.
+* :mod:`repro.obs.tracer` — :func:`annotate`, the always-on span on the
+  profiler's clock that every service, executor and pipeline phase runs
+  under; :func:`host_read`, the one door of device→host reads, each
+  under a ``host.sync`` span; and :class:`Span`/:class:`Tracer`: nested
+  phase spans (count-exchange, forward shuffle, probe, scatter, retry
+  attempts, taint sweeps) hanging off each
+  :class:`~repro.core.executor.JobRecord`, recorded only when a tracer is
+  held.  Outputs are bit-identical either way.
 * :mod:`repro.obs.metrics` — counters / gauges / HDR-style histograms in
   one ``msj.* / svc.* / ft.*`` namespace, absorbing the service, cache,
   and fault-tolerance counters, plus a JSONL sink.
@@ -31,4 +34,4 @@ from repro.obs.perfetto import (  # noqa: F401
     validate_trace,
     write_trace,
 )
-from repro.obs.tracer import Span, Tracer  # noqa: F401
+from repro.obs.tracer import Span, Tracer, annotate, host_read, phase  # noqa: F401

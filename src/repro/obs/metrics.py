@@ -6,8 +6,7 @@ grew in ``SGFService.counters()``, ``PlanCache``, ``ResultCache``, and
 
 * ``msj.*`` — engine-level work: ``msj.jobs``, ``msj.shuffle.bytes``
 * ``svc.*`` — service layers: ``svc.plan_cache.hit``,
-  ``svc.result_cache.query.hit``, ``svc.tick.latency`` (histogram),
-  ``svc.request.latency`` (histogram), ``svc.req.failed``, …
+  ``svc.result_cache.query.hit``, ``svc.req.failed``, …
 * ``ft.*`` — fault tolerance: ``ft.fault.injected``, ``ft.taint.jobs``,
   ``ft.capacity.retries``, ``ft.shard.losses``, …
 

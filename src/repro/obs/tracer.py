@@ -1,4 +1,16 @@
-"""Phase-span tracing for the executor's event timeline (DESIGN.md §14).
+"""Spans on the profiler's clock, and phase-span trees for the executor's
+event timeline (DESIGN.md §14).
+
+:func:`annotate` is the one span primitive: a
+``jax.profiler.TraceAnnotation``, always on.  With no profiler session
+open it costs about a microsecond and records nothing; under
+``jax.profiler.start_trace`` it lands in the trace's host plane on the
+same clock as the device's operations, which is what lets a reader say
+what the host was doing while the device sat idle and which phase
+launched each executable.  The service, the executor and every MSJ/EVAL
+pipeline stage run under such spans whether or not a :class:`Tracer` is
+held; :func:`host_read` puts every blocking device→host read under a
+``host.sync`` span of its own.
 
 A :class:`Span` is one named interval *inside* a job attempt — a pipeline
 stage (``msj.shuffle.fwd``, ``msj.probe``), a retry attempt
@@ -10,22 +22,54 @@ position, and they are rescaled whenever the executor rescales the job's
 wall (``wall_scale`` straggler injection, speculation-loser truncation),
 keeping every child interval inside its parent.
 
-The contract with the hot path: *every* tracing call site guards on
-``tracer is None`` (or ``tracer.enabled``) before doing any work, so the
-untraced build executes the identical instruction stream — bench numbers
-and outputs are bit-identical with ``tracer=None``.  A *traced* run also
-executes the identical instruction stream by default: spans bracket
-dispatch without syncing between stages, so enabling the tracer cannot
-serialize shuffle/compute overlap (DESIGN.md §16) or change what it
-measures.  ``Tracer(trace_sync=True)`` opts into the old
-block-until-ready-per-stage behaviour when honest per-phase *device*
-walls matter more than fidelity of the schedule being observed.
+The contract with the hot path: ``Span`` trees exist only when a
+:class:`Tracer` is held and enabled (:func:`phase` checks), and no span,
+profiler annotation or ``Span`` alike, syncs the device or changes what
+is dispatched — outputs are bit-identical with ``tracer=None``, with a
+tracer, and under the profiler.
 """
 from __future__ import annotations
 
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+
+def annotate(name: str, **args) -> TraceAnnotation:
+    """A span named ``name`` on the profiler's clock, with ``args`` as its
+    stats; use as a context manager.  Always active, and nearly free with
+    no profiler session open.  ``set_metadata(**more)`` on the entered
+    span adds stats known only at its end."""
+    return TraceAnnotation(name, **args)
+
+
+def host_read(x, site: str) -> np.ndarray:
+    """Read a device value to the host under a ``host.sync`` span naming
+    its ``site``: the one door of every blocking device→host read on the
+    served path, so a trace counts them and charges the idle time they
+    cause to them.  A value already on the host passes through with no
+    span."""
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    with annotate("host.sync", site=site):
+        return np.asarray(x)
+
+
+@contextmanager
+def phase(tracer, name: str, cat: str = "phase", **args):
+    """Run a phase under a profiler span and, when ``tracer`` is held and
+    enabled, under a recorded :class:`Span` too.  Yields the ``Span``, or
+    None when there is no tracer to record it."""
+    if tracer is not None and tracer.enabled:
+        with tracer.span(name, cat, **args) as sp:
+            yield sp
+    else:
+        with annotate(name, **args):
+            yield None
 
 
 @dataclass
@@ -72,24 +116,15 @@ class Tracer:
 
     ``capture()`` opens a fresh collection root (one per job attempt in
     the executor) and yields the list spans land in; ``span(name)`` times
-    a phase and nests it under the innermost open span.  A tracer is
-    reusable and single-threaded — the executor dispatches jobs serially
-    on this container, so one stack suffices.
+    a phase, nests it under the innermost open span, and annotates it on
+    the profiler's clock.  A tracer is reusable and single-threaded — the
+    executor dispatches jobs serially on this container, so one stack
+    suffices.
     """
 
-    def __init__(self, enabled: bool = True, *, trace_sync: bool = False):
+    def __init__(self, enabled: bool = True):
         self.enabled = enabled
-        #: opt-in per-stage barrier in the pipeline runner: attributes
-        #: device time to phases at the cost of serializing the dispatch
-        #: stream (and any comm/compute overlap).  Default off — tracing
-        #: must not perturb the schedule it measures.
-        self.trace_sync = trace_sync
         self._stack: list[list[Span]] = []
-
-    def current(self) -> list[Span]:
-        """The span list currently being appended to (for post-hoc
-        annotation of just-recorded spans, e.g. shuffle byte counts)."""
-        return self._stack[-1] if self._stack else []
 
     @contextmanager
     def capture(self):
@@ -118,7 +153,8 @@ class Tracer:
         self._stack[-1].append(sp)
         self._stack.append(sp.children)
         try:
-            yield sp
+            with annotate(name, **args):
+                yield sp
         finally:
             self._stack.pop()
             sp.dur = time.perf_counter() - sp.t0

@@ -50,6 +50,7 @@ from repro.core.planner import (
 from repro.core.relation import Relation
 from repro.engine.comm import Comm, SimComm
 from repro.obs.metrics import MetricRegistry, counter_attr
+from repro.obs.tracer import annotate, host_read
 from repro.service.catalog import Catalog, query_deps
 from repro.service.plan_cache import PlanCache, canonical_query_key, canonicalize
 from repro.service.result_cache import ResultCache, xmat_content_key
@@ -294,23 +295,24 @@ class SGFService:
     def submit(
         self, queries: Sequence[BSGF] | SGF | BSGF, *, tenant: int = 0
     ) -> QueryRequest:
-        self._check_quarantine(tenant)
-        if isinstance(queries, BSGF):
-            queries = [queries]
-        elif isinstance(queries, SGF):
-            queries = list(queries.queries)
-        else:
-            queries = list(queries)
-        names = [q.name for q in queries]
-        if len(set(names)) != len(names):
-            # fusion alpha-renames before SGF's own duplicate check could
-            # run; catch it here or the earlier duplicate silently loses
-            raise ValueError(f"duplicate output names in request: {names}")
-        self.catalog.validate(queries)
-        req = QueryRequest(self._next_rid, tuple(queries), tenant=tenant)
-        self._next_rid += 1
-        self.batcher.submit(req)
-        return req
+        with annotate("svc.submit", rid=self._next_rid, tenant=tenant):
+            self._check_quarantine(tenant)
+            if isinstance(queries, BSGF):
+                queries = [queries]
+            elif isinstance(queries, SGF):
+                queries = list(queries.queries)
+            else:
+                queries = list(queries)
+            names = [q.name for q in queries]
+            if len(set(names)) != len(names):
+                # fusion alpha-renames before SGF's own duplicate check could
+                # run; catch it here or the earlier duplicate silently loses
+                raise ValueError(f"duplicate output names in request: {names}")
+            self.catalog.validate(queries)
+            req = QueryRequest(self._next_rid, tuple(queries), tenant=tenant)
+            self._next_rid += 1
+            self.batcher.submit(req)
+            return req
 
     def _check_quarantine(self, tenant: int) -> None:
         """Gate admission on quarantine; expiry is the *decayed
@@ -472,19 +474,20 @@ class SGFService:
         into the cache for later ticks.
         """
         canon = list(batch.queries)
-        meta = self._closures(batch)
-        # sweep entries orphaned by catalog mutations (they can never hit
-        # again but would pin their arrays until LRU pressure)
-        self.results.evict_stale(self.catalog.rel_epochs)
-        warm: dict[str, Relation] = {}
-        cold: list[BSGF] = []
-        for q in canon:
-            blob, deps = meta[q.name]
-            rel = self.results.get("query", blob, self.catalog.dep_epochs(deps))
-            if rel is None:
-                cold.append(q)
-            else:
-                warm[q.name] = rel.rename(q.name)
+        with annotate("svc.lookup"):
+            meta = self._closures(batch)
+            # sweep entries orphaned by catalog mutations (they can never
+            # hit again but would pin their arrays until LRU pressure)
+            self.results.evict_stale(self.catalog.rel_epochs)
+            warm: dict[str, Relation] = {}
+            cold: list[BSGF] = []
+            for q in canon:
+                blob, deps = meta[q.name]
+                rel = self.results.get("query", blob, self.catalog.dep_epochs(deps))
+                if rel is None:
+                    cold.append(q)
+                else:
+                    warm[q.name] = rel.rename(q.name)
         self.last_tick = info = {
             "canonical_queries": len(canon),
             "warm_queries": len(warm),
@@ -498,76 +501,84 @@ class SGFService:
         # relations with exact statistics (their rows are resident)
         cold_deps = frozenset().union(*(meta[q.name][1] for q in cold))
         warm_read = {r for q in cold for r in q.relations} & set(warm)
-        stats = copy.deepcopy(self.catalog.stats())
-        for name in warm_read:
-            stats.register_output(name, float(warm[name].count()), warm[name].arity)
-        # the epoch key also pins *which queries* occupy the warm slots the
-        # cold batch reads (their closure blobs): an identical-looking cold
-        # batch fed by a differently-defined warm upstream must not reuse a
-        # plan costed with the old upstream's cardinality.  It also pins
-        # the skew decision (DESIGN.md §17): the defense annotates the
-        # trimmed plan per tick from hitter evidence, so a config/sketch
-        # flip must not serve a plan whose annotation era differs
-        epoch_key = (
-            self.catalog.dep_epochs(cold_deps),
-            tuple(sorted((n, meta[n][0]) for n in warm_read)),
-            ("skew", self.config.skew_defense, self.catalog.heavy_hitters),
-        )
-        plan, _hit = self.cache.get_or_plan(
-            cold,
-            epoch_key,
-            lambda: self._plan_batch(cold, copy.deepcopy(stats)),
-            canonical=True,
-        )
+        with annotate("svc.plan") as span:
+            stats = copy.deepcopy(self.catalog.stats())
+            for name in warm_read:
+                rows = host_read(warm[name].count(), "batcher.warm_rows")
+                stats.register_output(name, float(rows), warm[name].arity)
+            # the epoch key also pins *which queries* occupy the warm slots the
+            # cold batch reads (their closure blobs): an identical-looking cold
+            # batch fed by a differently-defined warm upstream must not reuse a
+            # plan costed with the old upstream's cardinality.  It also pins
+            # the skew decision (DESIGN.md §17): the defense annotates the
+            # trimmed plan per tick from hitter evidence, so a config/sketch
+            # flip must not serve a plan whose annotation era differs
+            epoch_key = (
+                self.catalog.dep_epochs(cold_deps),
+                tuple(sorted((n, meta[n][0]) for n in warm_read)),
+                ("skew", self.config.skew_defense, self.catalog.heavy_hitters),
+            )
+            plan, hit = self.cache.get_or_plan(
+                cold,
+                epoch_key,
+                lambda: self._plan_batch(cold, copy.deepcopy(stats)),
+                canonical=True,
+            )
+            span.set_metadata(hit=hit)
 
         local_names = set(warm) | {q.name for q in cold}
-        plan, injected = self._trim_plan(plan, local_names)
-        info["x_injected"] = len(injected)
-        if self.config.skew_defense:
-            # annotate AFTER trimming — _trim_plan rebuilds MSJ jobs from
-            # their surviving equations, which would drop any earlier
-            # annotation; the evidence is the catalog's heavy-hitter
-            # sketch (Catalog(heavy_hitters=k)), absent which no job ever
-            # qualifies and the defense is a structural no-op
-            plan = annotate_skew(
-                plan, stats, self.catalog.P, packing=self.config.packing
+        with annotate("svc.trim"):
+            plan, injected = self._trim_plan(plan, local_names)
+            info["x_injected"] = len(injected)
+            if self.config.skew_defense:
+                # annotate AFTER trimming — _trim_plan rebuilds MSJ jobs from
+                # their surviving equations, which would drop any earlier
+                # annotation; the evidence is the catalog's heavy-hitter
+                # sketch (Catalog(heavy_hitters=k)), absent which no job ever
+                # qualifies and the defense is a structural no-op
+                plan = annotate_skew(
+                    plan, stats, self.catalog.P, packing=self.config.packing
+                )
+                info["skew_defended"] = sum(
+                    1 for rnd in plan.rounds for job in rnd.jobs
+                    if isinstance(job, MSJJob) and job.skew is not None
+                )
+        with annotate("svc.verify"):
+            self._verify_plan(plan, warm, injected)
+        with annotate("svc.execute"):
+            # injected X relations must be visible to the scheduler's LPT cost
+            # estimates; ``stats`` is tick-private (the planner lambda took its
+            # own copy) and the scheduler copies again before mutating
+            for name, rel in injected.items():
+                rows = host_read(rel.count(), "batcher.injected_rows")
+                stats.register_output(name, float(rows), rel.arity)
+            # stats also feed the executor's per-job "auto" backend decision
+            # lineage = the catalog's durable relations only: warm/injected
+            # entries are cache-resident copies whose loss is indistinguishable
+            # from a cold miss, but base-relation shards re-materialize from
+            # the catalog rows bit-identically (DESIGN.md §13)
+            ex = Executor(
+                {**self.catalog.db(), **warm, **injected}, self.comm, self.config,
+                stats=stats, lineage=self.catalog.db(),
+                tracer=self.tracer, metrics=self.metrics,
             )
-            info["skew_defended"] = sum(
-                1 for rnd in plan.rounds for job in rnd.jobs
-                if isinstance(job, MSJJob) and job.skew is not None
+            self._executor = ex  # chaos injectors reach the live env here
+            sched = SlotScheduler(
+                ex,
+                slots=self.slots,
+                stats=stats,
+                consts=self.consts,
+                model=self.model,
             )
-        self._verify_plan(plan, warm, injected)
-        # injected X relations must be visible to the scheduler's LPT cost
-        # estimates; ``stats`` is tick-private (the planner lambda took its
-        # own copy) and the scheduler copies again before mutating
-        for name, rel in injected.items():
-            stats.register_output(name, float(rel.count()), rel.arity)
-        # stats also feed the executor's per-job "auto" backend decision
-        # lineage = the catalog's durable relations only: warm/injected
-        # entries are cache-resident copies whose loss is indistinguishable
-        # from a cold miss, but base-relation shards re-materialize from
-        # the catalog rows bit-identically (DESIGN.md §13)
-        ex = Executor(
-            {**self.catalog.db(), **warm, **injected}, self.comm, self.config,
-            stats=stats, lineage=self.catalog.db(),
-            tracer=self.tracer, metrics=self.metrics,
-        )
-        self._executor = ex  # chaos injectors reach the live env here
-        sched = SlotScheduler(
-            ex,
-            slots=self.slots,
-            stats=stats,
-            consts=self.consts,
-            model=self.model,
-        )
-        try:
-            env, report = sched.execute(
-                plan, on_job=self.on_job, max_restarts=self.max_restarts
-            )
-        finally:
-            self._executor = None
+            try:
+                env, report = sched.execute(
+                    plan, on_job=self.on_job, max_restarts=self.max_restarts
+                )
+            finally:
+                self._executor = None
         tainted = report.tainted_relations()
-        self._insert_results(plan, cold, meta, local_names, env, tainted)
+        with annotate("svc.insert"):
+            self._insert_results(plan, cold, meta, local_names, env, tainted)
         return env, report
 
     def _verify_plan(self, plan: Plan, warm: dict, injected: dict) -> None:
@@ -650,53 +661,50 @@ class SGFService:
         failed requests are excluded — they carry ``failures``/``error``).
         """
         self.tick_no += 1
-        self._readmit_delayed()
-        admitted = self.batcher.drain()
-        if not admitted:
-            return []
-        prev_tick = self.last_tick
-        try:
-            batch = fuse_requests(admitted)
-            env, report = self._run_batch(batch)
-        except Exception:
-            # don't lose co-admitted tenants to one failing tick (e.g. a
-            # CapacityFault after max retries under fail_policy="abort"):
-            # put the batch back in FIFO order so a caller can retry or
-            # re-admit after fixing capacity; last_tick must keep
-            # describing the last *successful* tick, like
-            # last_report/last_batch.  requeue (not a raw splice) so a
-            # request that also sits in the delayed queue can't collide
-            # into a duplicate
-            self.last_tick = prev_tick
-            self.batcher.requeue(admitted, front=True)
-            raise
-        poisoned = report.tainted_relations() & {q.name for q in batch.queries}
-        completed: list[QueryRequest] = []
-        for req in batch.requests:
-            mine = {batch.out_map[(req.rid, q.name)] for q in req.queries}
-            hit = sorted(mine & poisoned)
-            if hit:
-                self._fail_request(req, hit)
-                continue
-            for q in req.queries:
-                cname = batch.out_map[(req.rid, q.name)]
-                req.outputs[q.name] = env[cname].rename(q.name)
-            req.done = True
-            completed.append(req)
-        self.last_tick["poisoned_queries"] = len(poisoned)
-        self.last_tick["failed_requests"] = len(batch.requests) - len(completed)
-        self.warm_served += self.last_tick.get("warm_queries", 0)
-        self.cold_executed += self.last_tick.get("cold_queries", 0)
-        # per-request tick latency: every request admitted this tick waited
-        # out the tick's net (critical-path) time, warm hits included
-        lat = self._net_time(report)
-        hist = self.metrics.histogram("svc.tick.latency")
-        for _ in batch.requests:
-            hist.observe(lat)
-        self.reports.append(report)
-        self.last_report = report
-        self.last_batch = batch
-        return completed
+        with annotate("svc.tick", tick=self.tick_no):
+            self._readmit_delayed()
+            admitted = self.batcher.drain()
+            if not admitted:
+                return []
+            prev_tick = self.last_tick
+            try:
+                with annotate("svc.fuse"):
+                    batch = fuse_requests(admitted)
+                env, report = self._run_batch(batch)
+            except Exception:
+                # don't lose co-admitted tenants to one failing tick (e.g. a
+                # CapacityFault after max retries under fail_policy="abort"):
+                # put the batch back in FIFO order so a caller can retry or
+                # re-admit after fixing capacity; last_tick must keep
+                # describing the last *successful* tick, like
+                # last_report/last_batch.  requeue (not a raw splice) so a
+                # request that also sits in the delayed queue can't collide
+                # into a duplicate
+                self.last_tick = prev_tick
+                self.batcher.requeue(admitted, front=True)
+                raise
+            poisoned = report.tainted_relations() & {q.name for q in batch.queries}
+            completed: list[QueryRequest] = []
+            with annotate("svc.scatter"):
+                for req in batch.requests:
+                    mine = {batch.out_map[(req.rid, q.name)] for q in req.queries}
+                    hit = sorted(mine & poisoned)
+                    if hit:
+                        self._fail_request(req, hit)
+                        continue
+                    for q in req.queries:
+                        cname = batch.out_map[(req.rid, q.name)]
+                        req.outputs[q.name] = env[cname].rename(q.name)
+                    req.done = True
+                    completed.append(req)
+            self.last_tick["poisoned_queries"] = len(poisoned)
+            self.last_tick["failed_requests"] = len(batch.requests) - len(completed)
+            self.warm_served += self.last_tick.get("warm_queries", 0)
+            self.cold_executed += self.last_tick.get("cold_queries", 0)
+            self.reports.append(report)
+            self.last_report = report
+            self.last_batch = batch
+            return completed
 
     def run(self) -> None:
         """Tick until the queue is empty."""
@@ -731,8 +739,4 @@ class SGFService:
         c["bytes_shuffled"] = sum(r.bytes_shuffled() for r in self.reports)
         c["net_time"] = sum(self._net_time(r) for r in self.reports)
         c["total_time"] = sum(r.total_time for r in self.reports)
-        lat = self.metrics.histogram("svc.tick.latency")
-        c["tick_latency_p50"] = lat.percentile(0.50)
-        c["tick_latency_p95"] = lat.percentile(0.95)
-        c["tick_latency_p99"] = lat.percentile(0.99)
         return c

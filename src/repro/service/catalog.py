@@ -25,6 +25,7 @@ import numpy as np
 from repro.core.algebra import BSGF, SGF
 from repro.core.costmodel import RelStats, Stats, stats_of_db
 from repro.core.relation import Relation
+from repro.obs.tracer import annotate, host_read
 
 
 class CatalogError(KeyError):
@@ -80,19 +81,21 @@ class Catalog:
                 f"relation name {name!r} is reserved for the service's "
                 "canonical query namespace (q<i>/v<i>)"
             )
-        if isinstance(rows, Relation):
-            if rows.P != self.P:
-                raise ValueError(
-                    f"relation {name!r} is sharded P={rows.P}, catalog has P={self.P}"
-                )
-            rel = rows.rename(name)
-        elif isinstance(rows, np.ndarray):
-            rel = Relation.from_numpy(name, rows, P=self.P, partition=partition)
-        else:
-            rel = Relation.from_tuples(name, rows, P=self.P)
-        self._rels[name] = rel
-        self.epoch += 1
-        self.rel_epochs[name] = self.epoch
+        with annotate("svc.register", rel=name):
+            if isinstance(rows, Relation):
+                if rows.P != self.P:
+                    raise ValueError(
+                        f"relation {name!r} is sharded P={rows.P}, catalog has "
+                        f"P={self.P}"
+                    )
+                rel = rows.rename(name)
+            elif isinstance(rows, np.ndarray):
+                rel = Relation.from_numpy(name, rows, P=self.P, partition=partition)
+            else:
+                rel = Relation.from_tuples(name, rows, P=self.P)
+            self._rels[name] = rel
+            self.epoch += 1
+            self.rel_epochs[name] = self.epoch
         return rel
 
     def register_many(self, rels: Mapping[str, object]) -> None:
@@ -163,7 +166,10 @@ class Catalog:
             )
         else:
             rels = {
-                name: RelStats(rows=float(r.count()), arity=r.arity)
+                name: RelStats(
+                    rows=float(host_read(r.count(), "catalog.stats")),
+                    arity=r.arity,
+                )
                 for name, r in self._rels.items()
             }
             st = Stats(rels, dict(self.sel), self.default_sel)

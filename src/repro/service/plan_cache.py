@@ -28,6 +28,7 @@ from repro.core.algebra import Atom, BSGF, Cond, Not, cond_atoms
 from repro.core.planner import Plan
 from repro.engine import hashing
 from repro.obs.metrics import MetricRegistry, counter_attr
+from repro.obs.tracer import host_read
 
 
 # --------------------------------------------------------------------------
@@ -117,7 +118,7 @@ def fingerprint_queries(queries: Sequence[BSGF], *, canonical: bool = False) -> 
     if words.size == 0:
         words = np.zeros(1, np.int32)
     h = hashing.hash_cols(jnp.asarray(words)[None, :])
-    return int(np.asarray(h)[0])
+    return int(host_read(h, "plan_cache.fingerprint")[0])
 
 
 # --------------------------------------------------------------------------

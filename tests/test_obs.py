@@ -199,15 +199,180 @@ class TestTracedExecutor:
                 assert sp.t0 >= -1e-9
         # executor published report-derived metrics into the registry
         assert m.counter("msj.jobs").value == rep1.n_jobs
-        assert m.histogram("msj.job.wall").count == len(
-            [r for r in rep1.records if r.outcome == "ok"]
-        )
+        assert m.counter("msj.shuffle.bytes").value == rep1.bytes_shuffled()
+        # shuffled bytes and hits reach the stage spans from the job's
+        # host-read stats, after the job: the stages read nothing
+        for r in rep1.records:
+            args = {sp.name: sp.args for sp in r.spans[-1].walk()}
+            if "msj.probe" in args:
+                assert args["msj.shuffle.fwd"]["bytes"] == r.stats["bytes_fwd"]
+                assert args["msj.scatter"]["bytes"] == r.stats["bytes_bwd"]
+                assert args["msj.probe"]["hits"] == r.stats["hits"]
+            else:
+                assert args["eval.shuffle"]["bytes"] == r.stats["bytes_fwd"]
 
     def test_disabled_tracer_records_nothing(self):
         db, plan = _tiny_setup()
         tr = Tracer(enabled=False)
         _, rep = Executor(dict(db), SimComm(2), tracer=tr).execute(plan)
         assert all(r.spans == [] for r in rep.records)
+
+
+# --------------------------------------------------------------------------
+# Spans on the profiler's clock (always on, tracer or not)
+# --------------------------------------------------------------------------
+
+#: span -> the span it must sit directly under (DESIGN.md §14)
+PARENT = {
+    "svc.fuse": "svc.tick", "svc.lookup": "svc.tick", "svc.plan": "svc.tick",
+    "svc.trim": "svc.tick", "svc.verify": "svc.tick", "svc.execute": "svc.tick",
+    "svc.insert": "svc.tick", "svc.scatter": "svc.tick",
+    "ft.attempt": "svc.execute", "msj.publish": "svc.execute",
+    "eval.publish": "svc.execute", "msj.count": "ft.attempt",
+    "msj.shuffle.fwd": "ft.attempt", "msj.probe": "ft.attempt",
+    "msj.scatter": "ft.attempt", "eval.shuffle": "ft.attempt",
+    "eval.reduce": "ft.attempt",
+}
+PROGRAM = ("svc.", "msj.", "eval.", "ft.", "host.")
+
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` under a profiler session; returns its result and the
+    host events ``(name, start_ns, dur_ns, stats)`` of the program's
+    spans, outer first at one start."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(Path(log_dir).rglob("*.xplane.pb"))[-1]
+    events = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(PROGRAM):
+                    events.append((e.name, e.start_ns, e.duration_ns,
+                                   dict(e.stats)))
+    return out, sorted(events, key=lambda e: (e[1], -e[2]))
+
+
+def _parents(events) -> list:
+    """The enclosing span's name (or None) of each event, by nesting on
+    the one host thread the service runs on."""
+    out, stack = [], []
+    for name, start, dur, _ in events:
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        out.append(stack[-1][0] if stack else None)
+        stack.append((name, start + dur))
+    return out
+
+
+def _service(tracer=None):
+    from repro.service import SGFService, catalog_from_numpy
+
+    q = BSGF("Z", ("x", "y"), Atom("R", "x", "y"),
+             all_of(Atom("S", "x"), Atom("T", "y")))
+    db_np = Q.gen_db([q], n_guard=96, n_cond=64)
+    svc = SGFService(catalog_from_numpy(db_np, P=2), tracer=tracer)
+    return svc, q, db_np
+
+
+def _cold_tick(svc, q, db_np):
+    """Reload S (a cold tick: every job runs) and serve one request."""
+    svc.catalog.register("S", db_np["S"])
+    req = svc.submit([q])
+    svc.tick()
+    return req
+
+
+class TestProfilerSpans:
+    def test_tick_spans_nest_on_the_profiler_clock(self, tmp_path, monkeypatch):
+        import repro.obs.tracer as tracer_mod
+
+        svc, q, db_np = _service()
+        svc.submit([q])
+        svc.tick()  # compile outside the session
+        syncs = {"n": 0}
+        real = tracer_mod.annotate
+
+        def counting(name, **args):
+            syncs["n"] += name == "host.sync"
+            return real(name, **args)
+
+        # host_read is the only caller of annotate through the module
+        # global that names host.sync
+        monkeypatch.setattr(tracer_mod, "annotate", counting)
+        _, events = _profiled(tmp_path, lambda: _cold_tick(svc, q, db_np))
+        names = [e[0] for e in events]
+        ticks = [e for e in events if e[0] == "svc.tick"]
+        assert [e[3]["tick"] for e in ticks] == [2]
+        for name in ("svc.register", "svc.submit"):
+            assert names.count(name) == 1
+        parents = _parents(events)
+        for (name, *_), parent in zip(events, parents):
+            if name in PARENT:
+                assert parent == PARENT[name], (name, parent)
+            elif name in ("svc.register", "svc.submit", "svc.tick"):
+                assert parent is None, (name, parent)
+        for name in PARENT:
+            if name != "msj.count":
+                assert name in names, name
+        plan = next(e for e in events if e[0] == "svc.plan")
+        assert plan[3]["hit"] == 0
+        assert next(e for e in events if e[0] == "svc.register")[3]["rel"] == "S"
+        # one host.sync span per device read through the helper, each
+        # inside the tick
+        assert 0 < names.count("host.sync") == syncs["n"]
+        tick_end = ticks[0][1] + ticks[0][2]
+        assert all(ticks[0][1] <= s and s + d <= tick_end
+                   for n, s, d, _ in events if n == "host.sync")
+
+    def test_host_read_one_sync_per_device_value(self, tmp_path):
+        import jax.numpy as jnp
+
+        from repro.obs import host_read
+
+        x = jnp.arange(6, dtype=jnp.int32)
+
+        def reads():
+            return [host_read(x.sum(), "a"), host_read(x, "b"),
+                    host_read(x * 2, "c"), host_read(7, "on the host")]
+
+        got, events = _profiled(tmp_path, reads)
+        assert [int(got[0]), got[1].tolist(), int(got[3])] == [15, list(range(6)), 7]
+        assert [(e[0], e[3]["site"]) for e in events] == [
+            ("host.sync", "a"), ("host.sync", "b"), ("host.sync", "c")]
+
+    def test_no_span_objects_without_tracer_and_bits_identical(
+        self, tmp_path, monkeypatch
+    ):
+        made = {"n": 0}
+        real_init = Span.__init__
+
+        def counting(self, *a, **k):
+            made["n"] += 1
+            real_init(self, *a, **k)
+
+        runs = {}
+        svc, q, db_np = _service()
+        svc.submit([q])
+        svc.tick()
+        monkeypatch.setattr(Span, "__init__", counting)
+        runs["plain"] = _cold_tick(svc, q, db_np)
+        runs["profiled"], _ = _profiled(tmp_path, lambda: _cold_tick(svc, q, db_np))
+        assert made["n"] == 0, "tracer=None must create no Span"
+        tsvc, _, _ = _service(Tracer())
+        runs["traced"] = _cold_tick(tsvc, q, db_np)
+        assert made["n"] > 0
+        want = runs["plain"].outputs["Z"]
+        for key in ("profiled", "traced"):
+            got = runs[key].outputs["Z"]
+            assert np.array_equal(np.asarray(got.data), np.asarray(want.data)), key
+            assert np.array_equal(np.asarray(got.valid), np.asarray(want.valid)), key
 
 
 # --------------------------------------------------------------------------
@@ -405,9 +570,9 @@ class TestServiceMetricPlumbing:
         assert svc.metrics.counter("svc.tick.warm_queries").value == 1
         assert svc.metrics.counter("svc.result_cache.query.hit").value == 1
         assert c["query_hits"] == 1
-        # per-request tick latency histogram, surfaced as percentiles
-        assert svc.metrics.histogram("svc.tick.latency").count == 2
-        assert c["tick_latency_p99"] >= c["tick_latency_p50"] >= 0.0
+        # per-tick accounting from the reports, one per tick
+        assert c["ticks"] == 2 and len(svc.reports) == 2
+        assert c["net_time"] >= 0.0 and c["total_time"] >= 0.0
         # executor metrics landed in the same registry
         assert svc.metrics.counter("msj.jobs").value > 0
 

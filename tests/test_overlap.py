@@ -9,7 +9,7 @@ straggler, and partial-failure runs, while the replay identities
 (W=∞ == net_time, W=1 == total_time) keep holding with sub-node records
 present and the happens-before sanitizer stays green while slices
 overlap.  Alongside ride the sync-path regressions: tracing must not
-insert per-stage barriers (``Tracer.trace_sync`` opt-in), the executor
+insert per-stage barriers, traced or with spans alone, the executor
 must not blanket-sync outputs (``sync_per_job`` defaults off), and a
 ``CapacityFault`` raised by a prefetched transfer must blame the
 transfer's own retry state — never the compute occupying the slot.
@@ -293,9 +293,10 @@ def test_overlap_double_buffer_bound_holds(c4_setup):
 
 
 def test_traced_pipeline_identical_stream_and_bits(monkeypatch):
-    """The traced SimComm run_pipeline path must issue the exact same
-    instruction stream as the untraced one — no per-stage barrier unless
-    Tracer(trace_sync=True) opts in — and the carries stay bit-identical."""
+    """The SimComm run_pipeline path must issue the same instruction
+    stream with or without a tracer — every stage runs under a profiler
+    span either way, and neither inserts a per-stage barrier — and the
+    carries stay bit-identical."""
     calls = {"n": 0}
     real = jax.block_until_ready
 
@@ -315,21 +316,19 @@ def test_traced_pipeline_identical_stream_and_bits(monkeypatch):
         return None, prev + recv.sum(axis=0)
 
     x = jnp.arange(P * 4, dtype=jnp.float32).reshape(P, 4)
-    plain = run_pipeline(comm, [stage_a, stage_b], x)
-    calls["n"] = 0
     traced = run_pipeline(
         comm, [stage_a, stage_b], x, tracer=Tracer(),
         names=["a", "b"],
     )
     assert calls["n"] == 0, "tracing must not sync between stages"
-    np.testing.assert_array_equal(np.asarray(plain), np.asarray(traced))
-    calls["n"] = 0
-    synced = run_pipeline(
-        comm, [stage_a, stage_b], x, tracer=Tracer(trace_sync=True),
+    plain = run_pipeline(comm, [stage_a, stage_b], x, names=["a", "b"])
+    disabled = run_pipeline(
+        comm, [stage_a, stage_b], x, tracer=Tracer(enabled=False),
         names=["a", "b"],
     )
-    assert calls["n"] == 2, "trace_sync=True restores the per-stage barrier"
-    np.testing.assert_array_equal(np.asarray(plain), np.asarray(synced))
+    assert calls["n"] == 0, "the stages' profiler spans must not sync"
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(traced))
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(disabled))
 
 
 def test_traced_execute_bit_identical_and_schedule_shaped(c4_setup, clean_runs):
