@@ -27,7 +27,7 @@ import jax
 
 from repro.core import queries as Q
 from repro.core.executor import Executor, ExecutorConfig
-from repro.core.planner import plan_one_round, plan_par, plan_greedy
+from repro.core.planner import EvalJob, plan_one_round, plan_par, plan_greedy
 from repro.core.costmodel import HADOOP, stats_of_db
 from repro.core.relation import db_from_dict
 from repro.engine.comm import SimComm
@@ -94,8 +94,9 @@ def run(n_guard: int = 8192, sel: float = 0.3, P: int = 16) -> list[dict]:
                 "jobs": int(s["jobs"]),
                 "net_s": float(report.net_time),
                 "total_s": float(report.total_time),
-                "forward_cap": max(
-                    (r.stats.get("forward_cap", 0) for r in report.records),
+                "forward_cap": max(  # MSJ's forward shuffle, not EVAL's
+                    (r.stats.get("forward_cap", 0) for r in report.records
+                     if not isinstance(r.job, EvalJob)),
                     default=0,
                 ),
             }

@@ -32,7 +32,7 @@ import numpy as np
 from repro.core.algebra import BSGF, Atom
 from repro.core.costmodel import stats_of_db
 from repro.core.executor import Executor, ExecutorConfig
-from repro.core.planner import MSJJob, annotate_skew, plan_par
+from repro.core.planner import EvalJob, MSJJob, annotate_skew, plan_par
 from repro.core.relation import db_from_dict
 from repro.engine.comm import SimComm
 
@@ -102,8 +102,9 @@ def run(n_guard: int = 4096, P: int = 8, seed: int = 7) -> list[dict]:
                 "exponent": s,
                 "variant": variant,
                 "bytes_shuffled": int(sm["bytes_shuffled"]),
-                "forward_cap": max(
-                    (r.stats.get("forward_cap", 0) for r in report.records),
+                "forward_cap": max(  # MSJ's forward shuffle, not EVAL's
+                    (r.stats.get("forward_cap", 0) for r in report.records
+                     if not isinstance(r.job, EvalJob)),
                     default=0,
                 ),
                 "R": max((a.R for a in ann), default=0) if on else 0,

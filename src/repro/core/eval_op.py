@@ -2,7 +2,8 @@
 ``Z_u := X0_u ∧ φ_u(X1_u ... Xn_u)`` (paper Section 4.3).
 
 Every row of every input relation is routed by a hash of its *tuple*
-(one all_to_all); on the receiving shard rows are grouped by
+(one all_to_all, its buckets sized from the rows each shard sends each
+destination); on the receiving shard rows are grouped by
 ``(unit, tuple)`` with a single lexicographic sort, each group's membership
 bitmask is formed with a segment-OR, and the Boolean formula is applied to
 the bitmask — exactly the paper's reducer, vectorized.
@@ -25,6 +26,7 @@ from repro.core.msj import _lex_order
 from repro.core.relation import Relation
 from repro.engine import hashing, shuffle
 from repro.engine.comm import Comm, run_pipeline
+from repro.obs.tracer import host_read, phase
 
 
 @dataclass(frozen=True)
@@ -71,14 +73,25 @@ def run_eval(
     comm: Comm,
     *,
     forward_cap: int | None = None,
+    count_sized: bool = True,
     tracer=None,
 ):
     """Execute one EVAL job. Returns ``({name: Relation}, stats)``.
 
+    The forward shuffle is count-sized, as MSJ's is (DESIGN.md §6): the
+    map stage also counts each shard's valid rows per destination, the
+    largest (source, destination) count is read once (``eval.count_cap``)
+    and rounded up to a power of two, and the partition and the reduce run
+    at that bucket capacity.  ``forward_cap`` overrides the count (the
+    executor's overflow ladder); ``count_sized=False``, or counts that are
+    traced values (MeshComm inside ``shard_map``), fall back to the
+    worst-case bound, the sum of the inputs' capacities.
+
     The two pipeline phases run under profiler spans (``eval.shuffle`` —
-    tuple routing + exchange — and ``eval.reduce`` — sorted grouping +
-    formula evaluation), which ``tracer`` also records as phase spans
-    (DESIGN.md §14); outputs are the same with or without it.
+    tuple routing, the count read and the exchange — and ``eval.reduce``
+    — sorted grouping + formula evaluation), which ``tracer`` also
+    records as phase spans (DESIGN.md §14); outputs are the same with or
+    without it.
     """
     P = comm.P
     units = tuple(units)
@@ -99,7 +112,7 @@ def run_eval(
             inputs.append((ui, mi + 1, x))
     rel_names = sorted({name for _, _, name in inputs})
 
-    cap_s = forward_cap or max(1, sum(env[name].cap for _, _, name in inputs))
+    bound = max(1, sum(env[name].cap for _, _, name in inputs))
     W = A + 2  # [unit, member, tuple cols...]
 
     def stage_map(sid, local_db):
@@ -129,6 +142,11 @@ def run_eval(
         msgs = jnp.concatenate(msgs, 0)
         valid = jnp.concatenate(valid, 0)
         dest = jnp.concatenate(dest, 0)
+        counts = jnp.bincount(jnp.where(valid, dest, P), length=P + 1)[:P]
+        return None, (msgs, valid, dest, counts.astype(jnp.int32))
+
+    def stage_partition(sid, mapped):
+        msgs, valid, dest, _ = mapped
         sent = valid.sum().astype(jnp.int32)
         buf, bufvalid, ovf, _ = shuffle.partition(msgs, valid, dest, P, cap_s)
         return (buf, bufvalid), (ovf, sent)
@@ -185,11 +203,38 @@ def run_eval(
         return None, (outs, stats)
 
     stacked = {name: env[name] for name in rel_names}
+    with phase(tracer, "eval.shuffle") as sp:
+        mapped = run_pipeline(comm, [stage_map], stacked)
+        cap_s, counted = _forward_cap(
+            mapped[3], bound, forward_cap=forward_cap, count_sized=count_sized
+        )
+        if sp is not None:
+            sp.args["cap"] = cap_s
+        exchanged = run_pipeline(comm, [stage_partition], mapped)
     outputs, stats = run_pipeline(
-        comm, [stage_map, stage_reduce], stacked,
-        tracer=tracer, names=["eval.shuffle", "eval.reduce"],
+        comm, [stage_reduce], exchanged, tracer=tracer, names=["eval.reduce"]
     )
     stats = {k: jnp.asarray(v).sum() for k, v in stats.items()}
-    stats["bytes_fwd"] = stats["sent_fwd"] * W * 4
+    # the counts read to size the buckets are P int32 per shard, as in
+    # MSJ's count phase; charged so count-sizing can't hide traffic
+    stats["bytes_fwd"] = stats["sent_fwd"] * W * 4 + (P * P * 4 if counted else 0)
     stats["bytes_bwd"] = jnp.int32(0)
+    stats["forward_cap"] = cap_s
     return outputs, stats
+
+
+def _forward_cap(
+    counts, bound: int, *, forward_cap: int | None, count_sized: bool
+) -> tuple[int, bool]:
+    """The per-destination bucket capacity of EVAL's forward shuffle and
+    whether it was read from ``counts`` (the per-shard, per-destination
+    valid-row counts): the override, else the largest count rounded up to
+    a power of two (so data of nearby sizes share compiled shapes) and
+    held to ``bound``, else ``bound`` when counting is off or the counts
+    are traced values."""
+    if forward_cap is not None:
+        return forward_cap, False
+    if not count_sized or isinstance(counts, jax.core.Tracer):
+        return bound, False
+    top = int(host_read(jnp.max(counts), "eval.count_cap"))
+    return min(1 << max(top - 1, 0).bit_length(), bound), True

@@ -494,8 +494,9 @@ class ExecutorConfig:
     #: (costmodel.choose_backend) from that job's RelStats — rows, key
     #: width, estimated selectivity — so one plan can mix backends.
     probe_backend: str = "auto"
-    #: two-phase count-sized forward shuffle (DESIGN.md §6); False restores
-    #: the worst-case default_forward_cap bound.
+    #: two-phase count-sized forward shuffle of MSJ and EVAL jobs
+    #: (DESIGN.md §6); False restores the worst-case bounds
+    #: (default_forward_cap; EVAL: the sum of its inputs' capacities).
     count_sized: bool = True
     #: (signature, key) fingerprint message layout (DESIGN.md §5); False
     #: restores the seed [kind, tag, key*KW, src, row] layout end to end.
@@ -909,7 +910,12 @@ class Executor:
                 )
             )
             input_rows += self._rows([x0], env) + self._rows(xin)
-        outs, stats = run_eval(env, units, self.comm, tracer=self.tracer)
+        outs, stats = run_eval(
+            env, units, self.comm,
+            forward_cap=cap_override,
+            count_sized=self.config.count_sized,
+            tracer=self.tracer,
+        )
         stats["input_rows"] = input_rows
         return outs, stats
 

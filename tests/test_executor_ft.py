@@ -250,3 +250,68 @@ def test_checkpoint_atomicity(tmp_path):
     assert checkpoint.latest_step(str(tmp_path)) == 1
     loaded = checkpoint.load(str(tmp_path), 1, tree)
     np.testing.assert_array_equal(np.asarray(loaded["a"]), np.ones((4,)))
+
+
+def test_eval_overflow_climbs_the_retry_ladder():
+    """An EVAL job forced to an undersized bucket reports overflow; the
+    RetryState ladder doubles the cap it ran at until every row fits, and
+    the outputs are the sound ones."""
+    from repro.core.planner import EvalJob
+
+    qs = Q.make_queries("A3")
+    db_np = Q.gen_db(qs, n_guard=64, n_cond=64)
+    seen = []
+
+    class Undersized(Executor):
+        def run_job(self, job, *, cap_override=None, cap_slack=None):
+            if isinstance(job, EvalJob) and cap_override is None:
+                cap_override = 1
+            outs, stats = super().run_job(
+                job, cap_override=cap_override, cap_slack=cap_slack
+            )
+            if isinstance(job, EvalJob):
+                seen.append((stats["forward_cap"], int(stats["overflow"])))
+            return outs, stats
+
+    ex = Undersized(db_from_dict(db_np, P=4), SimComm(4),
+                    ExecutorConfig(max_retries=12))
+    env, report = ex.execute(plan_par(qs))
+    caps = [c for c, _ in seen]
+    assert caps == [2 ** i for i in range(len(caps))] and len(caps) > 1
+    assert all(o > 0 for _, o in seen[:-1]) and seen[-1][1] == 0
+    rec = next(r for r in report.records if isinstance(r.job, EvalJob))
+    assert rec.attempts == len(caps)
+    assert ex.ft_counters["overflow_retries"] == len(caps) - 1
+    assert env["Z"].to_set() == _want(qs, db_np)["Z"]
+
+
+@pytest.mark.parametrize("count_sized", [True, False])
+def test_eval_cap_follows_count_sized(count_sized, monkeypatch):
+    """``ExecutorConfig.count_sized`` governs EVAL as it does MSJ: on, one
+    ``eval.count_cap`` read sizes the buckets; off, no read and the cap is
+    the sum of the EVAL inputs' capacities."""
+    from repro.core import eval_op
+    from repro.core.planner import EvalJob
+
+    qs = Q.make_queries("A3")
+    db_np = Q.gen_db(qs, n_guard=128, n_cond=128)
+    sites = []
+    real = eval_op.host_read
+    monkeypatch.setattr(
+        eval_op, "host_read", lambda x, site: sites.append(site) or real(x, site)
+    )
+    ex = Executor(db_from_dict(db_np, P=4), SimComm(4),
+                  ExecutorConfig(count_sized=count_sized))
+    env, report = ex.execute(plan_par(qs))
+    (rec,) = [r for r in report.records if isinstance(r.job, EvalJob)]
+    bound = sum(
+        env[q.guard.rel].cap + sum(env[x].cap for x in xin)
+        for q, xin in zip(rec.job.queries, rec.job.atom_inputs)
+    )
+    if count_sized:
+        assert sites == ["eval.count_cap"]
+        assert rec.stats["forward_cap"] < bound
+    else:
+        assert sites == []
+        assert rec.stats["forward_cap"] == bound
+    assert env["Z"].to_set() == _want(qs, db_np)["Z"]
